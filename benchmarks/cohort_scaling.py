@@ -38,7 +38,9 @@ A fourth row family, ``cohort.sharded.D{n}``, measures the mesh-sharded
 engine (clients/sec at 1/2/4/8 forced host devices on the
 mega_constellation skewed shape, C=256 mlp by default).  Each device
 count runs in a ``--sharded-worker`` subprocess because
-``--xla_force_host_platform_device_count`` binds at jax import; rows
+``--xla_force_host_platform_device_count`` binds at jax import.  These
+are CPU-only virtual-device rows (the workers are pinned to
+``JAX_PLATFORMS=cpu``), and a worker that fails fails the module; rows
 carry per-shard padding/imbalance metrics from
 ``CohortEngineStats``.  The D8 gate requires >= 1.5x round throughput
 over D1 wherever >= 2 usable cores exist; a 1-core host serializes the
@@ -317,7 +319,9 @@ def _sharded_rows(args) -> int:
     """Emit the ``cohort.sharded.D{n}`` row family and apply the D8
     scaling gate.  Each device count runs in its own subprocess because
     ``--xla_force_host_platform_device_count`` only takes effect before
-    the first jax import."""
+    the first jax import.  These are CPU-only rows: every worker is
+    forced onto virtual host devices (``JAX_PLATFORMS=cpu``), so they
+    measure the sharded program's structure, never a chip."""
     import json
     import subprocess
     devices = args.sharded_devices or ([1, 2] if args.smoke
@@ -332,7 +336,7 @@ def _sharded_rows(args) -> int:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + f" --xla_force_host_platform_device_count={n}"
                             ).strip()
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"  # virtual host devices only
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.join(root, "src")]
             + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
@@ -344,9 +348,12 @@ def _sharded_rows(args) -> int:
         proc = subprocess.run(cmd, env=env, cwd=root,
                               capture_output=True, text=True)
         if proc.returncode != 0:
+            # a missing device count is a failed measurement, not a
+            # smaller sweep: fail the module so no partial row family
+            # becomes a baseline
             print(f"sharded D{n} worker failed:\n{proc.stderr}",
                   file=sys.stderr)
-            continue
+            return 1
         res = json.loads(proc.stdout.strip().splitlines()[-1])
         results[n] = res
         speed = (results[1]["steady_s"] / res["steady_s"]

@@ -106,6 +106,8 @@ def main() -> None:
     # module mains parse sys.argv themselves; hide the driver's flags
     sys.argv = [sys.argv[0]]
 
+    from repro.compat import setup_compile_cache
+    print(f"# {setup_compile_cache()}", flush=True)
     print("name,us_per_call,derived")
     failures = []
     rows_by_module = {}

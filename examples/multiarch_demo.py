@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import setup_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.models import transformer as T
 
@@ -51,6 +52,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=ARCH_IDS)
     args = ap.parse_args()
+    print(setup_compile_cache())
     for arch in ([args.arch] if args.arch else ARCH_IDS):
         run(arch)
 

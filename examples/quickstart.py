@@ -2,12 +2,14 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from repro.compat import setup_compile_cache
 from repro.core import build_default_sagin, optimize_offloading
 from repro.core.latency import round_latency_no_offload
 from repro.fl import FLConfig, run_fl
 
 
 def main():
+    print(setup_compile_cache())
     # --- 1. the paper's core: one adaptive data-offloading decision -------
     sagin = build_default_sagin(n_devices=10, n_air=2, seed=0)
     baseline = round_latency_no_offload(sagin)

@@ -49,6 +49,7 @@ Summarize with ``python -m repro.obs report PATH``.  Pair with
 import argparse
 import dataclasses
 
+from repro.compat import setup_compile_cache
 from repro.fl import FLConfig, run_fl
 from repro.scenarios import get_scenario, list_scenarios
 
@@ -108,6 +109,7 @@ def main():
                          "--xla_force_host_platform_device_count=N")
     ap.add_argument("--list-scenarios", action="store_true")
     args = ap.parse_args()
+    print(setup_compile_cache())
 
     if args.list_scenarios:
         for name in list_scenarios():

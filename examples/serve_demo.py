@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import setup_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.models import transformer as T
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
+    print(setup_compile_cache())
 
     cfg = get_config(args.arch).reduced()
     rng = np.random.default_rng(0)

@@ -1,53 +1,56 @@
-"""JAX-version compatibility shims.
+"""The one home of JAX-API choices the rest of the codebase builds on.
 
-The reproduction targets a range of JAX releases, and two APIs it relies on
-moved/changed shape across that range:
+* ``shard_map`` is ``jax.shard_map``.
+* ``make_mesh`` builds meshes whose axes are all ``AxisType.Auto``:
+  ``jax.make_mesh`` defaults to Explicit axes, under which the sharding
+  constraints and gathers of the model code are refused.
+* ``cost_analysis`` returns ``Compiled.cost_analysis()`` as a flat dict.
+* ``setup_compile_cache`` places JAX's persistent compilation cache.
 
-* ``shard_map`` graduated from ``jax.experimental.shard_map.shard_map``
-  to top-level ``jax.shard_map`` (jax >= 0.4.35 exposes one or the other,
-  newer releases only the top-level name).
-* ``Compiled.cost_analysis()`` historically returned a list with one dict
-  per program, and newer releases return the dict directly.
-
-Everything that touches either API goes through this module so the rest of
-the codebase can be written against a single stable surface.
+Everything that touches these APIs goes through this module so the rest
+of the codebase is written against a single surface.
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+import os
+from pathlib import Path
+from typing import Dict, Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "normalize_cost_analysis", "cost_analysis"]
+__all__ = ["shard_map", "make_mesh", "cost_analysis", "setup_compile_cache",
+           "CHECKOUT_CACHE_DIR"]
 
+shard_map = jax.shard_map
 
-def _resolve_shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm  # noqa: F811
-    return sm
-
-
-#: Version-stable ``shard_map`` (prefers ``jax.shard_map``, falls back to
-#: ``jax.experimental.shard_map.shard_map`` on older releases).
-shard_map = _resolve_shard_map()
+#: The compile cache's fixed in-checkout home (``<checkout>/.jax_cache``):
+#: no temp name, pid or time, so a later run finds what an earlier stored.
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def normalize_cost_analysis(cost: Any) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` output to a flat dict.
-
-    Accepts the raw return value in any of its historical shapes
-    (``None``, ``{...}``, or ``[{...}]``) and always returns a dict, so
-    callers can do ``cost["flops"]`` regardless of the JAX version.
-    """
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` with the version shim applied."""
-    return normalize_cost_analysis(compiled.cost_analysis())
+    """``compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return dict(compiled.cost_analysis() or {})
+
+
+def setup_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and say where it is.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads the variable itself
+    and nothing is set here.  Otherwise the cache goes to the fixed
+    :data:`CHECKOUT_CACHE_DIR`.  Returns a one-line description of the
+    choice in effect."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return f"compile cache: {env} (from JAX_COMPILATION_CACHE_DIR)"
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return f"compile cache: {CHECKOUT_CACHE_DIR} (in-checkout default)"

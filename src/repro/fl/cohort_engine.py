@@ -444,7 +444,11 @@ class CohortEngine:
         def bucket_step(params, xs, ys, mask, weights, lr):
             # per-shard slice of the bucket: local updates over this
             # shard's clients, then the shard's weighted partial sum
-            # combined across the data axis — no host round-trip
+            # combined across the data axis — no host round-trip.  The
+            # replicated params are cast to varying over "data" first:
+            # the gradient of an unvarying value is psum'd over the axis,
+            # which would fold every shard's gradients into each client
+            params = jax.lax.pcast(params, "data", to="varying")
             stacked, losses = cohort_local_update(apply_fn, params, xs,
                                                   ys, mask, lr)
             part = shard_weighted_aggregate(stacked, weights,

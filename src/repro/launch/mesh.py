@@ -11,8 +11,8 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import shard_map  # noqa: F401  (version-stable re-export
-#                                    for mesh programs; see repro.compat)
+from repro.compat import make_mesh, shard_map  # noqa: F401  (shard_map is
+#                                    re-exported for mesh programs)
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_cohort_mesh",
            "shard_map", "PEAK_FLOPS_BF16", "HBM_BW", "ICI_BW"]
@@ -21,12 +21,12 @@ __all__ = ["make_production_mesh", "make_host_mesh", "make_cohort_mesh",
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for CPU smoke tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_cohort_mesh(n_devices=None):
@@ -38,7 +38,7 @@ def make_cohort_mesh(n_devices=None):
     n = len(devices) if n_devices is None else int(n_devices)
     if not 1 <= n <= len(devices):
         raise ValueError(f"n_devices={n} not in [1, {len(devices)}]")
-    return jax.make_mesh((n,), ("data",), devices=devices[:n])
+    return make_mesh((n,), ("data",), devices=devices[:n])
 
 
 # TPU v5e hardware constants for the roofline model (per chip)

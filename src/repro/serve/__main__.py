@@ -34,11 +34,13 @@ def main(argv=None) -> int:
                     help="JSONL trace path (training + serving spans)")
     args = ap.parse_args(argv)
 
+    from repro.compat import setup_compile_cache
     from repro.fl.rounds import FLConfig
     from repro.scenarios import get_scenario
     from repro.serve.gateway import ServeGateway, resolve_serve
     from repro.sim.engine import SAGINEngine
 
+    print(f"# {setup_compile_cache()}", flush=True)
     try:
         scn = get_scenario(args.scenario)
     except ValueError as e:

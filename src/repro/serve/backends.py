@@ -77,7 +77,8 @@ class TransformerBackend:
         self.cfg = cfg
         self.seq_len = int(seq_len)
         self.donate = donate
-        self.mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.compat import make_mesh
+        self.mesh = make_mesh((1, 1), ("data", "model"))
         from repro.models import transformer as T
         self.params = T.init_params(cfg, jax.random.PRNGKey(seed))
         self._steps: Dict[int, object] = {}   # padded width -> jitted step

@@ -65,9 +65,10 @@ COLLECTIVE_TEST = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.compat import make_mesh
     from repro.launch import hlo_analysis as H
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     sh = NamedSharding(mesh, P(None, "data"))
     x = jax.ShapeDtypeStruct((256, 256), jnp.float32)
 

@@ -14,7 +14,9 @@ from repro.kernels.wkv6 import kernel as wkv_k, ref as wkv_r
 # fedavg_agg ------------------------------------------------------------------
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("shape", [(1, 7), (3, 100), (5, 128, 33),
-                                   (2, 16384), (4, 3, 5, 7)])
+                                   (2, 16384), (4, 3, 5, 7),
+                                   # several client tiles, ragged tail
+                                   (64, 257), (70, 3, 100)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fedavg_agg_sweep(shape, dtype):
     rng = np.random.default_rng(0)

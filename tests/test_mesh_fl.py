@@ -11,11 +11,11 @@ PSUM_TEST = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map  # jax.shard_map moved across versions
+    from repro.compat import make_mesh, shard_map
     from repro.fl.aggregation import hierarchical_weighted_psum
     from repro.launch.train import make_replica_agg_step
 
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     # each (pod, data) shard holds its own "client model" scalar
     vals = jnp.arange(8, dtype=jnp.float32).reshape(2, 4)
 
@@ -43,6 +43,7 @@ FL_STEP_TEST = textwrap.dedent("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config
+    from repro.compat import make_mesh
     from repro.configs.shapes import InputShape
     from repro.launch.train import make_fl_train_step, abstract_params
     from repro.models import transformer as T
@@ -51,7 +52,7 @@ FL_STEP_TEST = textwrap.dedent("""
         get_config("olmo-1b").reduced(n_layers=2, d_model=128),
         param_dtype="float32")
     shape = InputShape("mini", 64, 8, "train")
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     with mesh:
         step, rep_sh, batch_sh = make_fl_train_step(cfg, mesh, shape,
                                                     lr=1e-2, h_local=2)

@@ -87,6 +87,7 @@ MINI_DRYRUN = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, numpy as np
+    from repro.compat import make_mesh
     from repro.configs import get_config
     from repro.configs.shapes import InputShape, input_specs
     from repro.launch.train import make_sharded_train_step, abstract_params
@@ -96,7 +97,7 @@ MINI_DRYRUN = textwrap.dedent("""
         get_config("llama3.2-3b").reduced(n_layers=2, d_model=128),
         param_dtype="float32")
     shape = InputShape("mini", 128, 8, "train")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     with mesh, activation_sharding(mesh, ("data",)):
         step, _, _ = make_sharded_train_step(cfg, mesh, shape)
         lowered = step.lower(abstract_params(cfg), input_specs(cfg, shape))
@@ -121,12 +122,13 @@ def test_serve_step_donate_false_keeps_cache_readable():
     cache it keeps by reference, so a silently donated buffer would
     poison the next dispatch of the same batch width."""
     import jax.numpy as jnp
+    from repro.compat import make_mesh
     from repro.configs.shapes import InputShape
     from repro.launch.serve import make_serve_step
     from repro.models import transformer as T
 
     cfg = get_config("llama3.2-3b").reduced(n_layers=2, d_model=64)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = InputShape("donate_smoke", 8, 2, "decode")
     step, _ = make_serve_step(cfg, mesh, shape, donate=False)
     params = T.init_params(cfg, jax.random.PRNGKey(0))

@@ -139,10 +139,10 @@ def test_jax_backend_without_x64_raises():
 
 
 def test_jax_backend_with_x64_matches_numpy_exactly():
-    from jax.experimental import enable_x64
+    import jax
     ws = WalkerStar(n_sats=20, n_planes=4)
     a = access_intervals_multi(ws, REGIONS, t_end=3600.0, backend="numpy")
-    with enable_x64():
+    with jax.enable_x64(True):
         b = access_intervals_multi(ws, REGIONS, t_end=3600.0, backend="jax")
     for r in REGIONS:
         assert_same_intervals(a[r.name], b[r.name])
