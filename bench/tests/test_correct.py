@@ -117,6 +117,61 @@ def test_train_altered_sample_is_not_correct(name, monkeypatch):
     assert not checks["cohort_faults"]["ok"]
 
 
+def _halved(cohort):
+    """The cohort with the second half of each step's valid samples
+    masked out, the recorded cohort left whole."""
+    import dataclasses
+    drv = core.driver("train")
+    return dataclasses.replace(cohort, buckets=[
+        dataclasses.replace(cb, mask=drv._faulted(cb.mask, "half_batch"))
+        for cb in cohort.buckets])
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+@pytest.mark.parametrize("name", TRAIN)
+def test_train_fault_in_round_2_only_is_not_correct(name, fault,
+                                                    monkeypatch):
+    """A fault planted in the second checked round alone, where round 1,
+    which is not gap-compared, runs sound."""
+    from repro.fl.cohort_engine import CohortEngine
+    inner = CohortEngine._execute
+    cell = _tiny(name)
+    n_regions = len(cell["scenario"]["regions"])
+    calls = []
+
+    def second_round(self, params, cohort, lr, total, **kw):
+        calls.append(None)
+        if not n_regions < len(calls) <= 2 * n_regions:
+            return inner(self, params, cohort, lr, total, **kw)
+        if fault == "half_batch":
+            return inner(self, params, _halved(cohort), lr, total, **kw)
+        _, losses = inner(self, params, cohort, lr, total, **kw)
+        return params, losses
+
+    monkeypatch.setattr(CohortEngine, "_execute", second_round)
+    result, checks = _run(cell)
+    assert len(calls) > 2 * n_regions
+    assert not result["correct"], checks
+
+
+@pytest.mark.parametrize("name", TRAIN)
+def test_train_model_kept_between_rounds_is_not_correct(name, monkeypatch):
+    """A region that trains its round but keeps its old model: a replay
+    from the recorded start would copy that, the hand-off check does
+    not."""
+    from repro.fl import rounds
+    inner = rounds._round_batched
+
+    def kept(cfg, apply_fn, params, *args, **kw):
+        _, losses, n_quar = inner(cfg, apply_fn, params, *args, **kw)
+        return params, losses, n_quar
+
+    monkeypatch.setattr(rounds, "_round_batched", kept)
+    result, checks = _run(_tiny(name))
+    assert not result["correct"]
+    assert not checks["handoff_faults"]["ok"]
+
+
 @pytest.mark.parametrize("name", TRAIN)
 def test_train_control_fails_the_limits(name):
     """The bf16 reference in the program's place, on the inputs the
@@ -124,12 +179,12 @@ def test_train_control_fails_the_limits(name):
     drv = core.driver("train")
     cell = _tiny(name)
     config = core.config(cell["config"])
-    engine, _ = drv.set_up(cell, config, SEED)
+    engine = drv.set_up(cell, config, SEED)
     rec = drv.checked_rounds(engine, cell["checked_rounds"], SEED)
     del engine
-    ref = drv.replay(config, cell, SEED, rec)
-    low = drv.replay(config, cell, SEED, rec, prec="bf16")
-    gaps = drv.gaps(low, ref, len(rec.rows))
+    ref = drv.replay(config, cell, rec)
+    low = drv.replay(config, cell, rec, prec="bf16")
+    gaps = drv.gaps(low, ref)
     assert "client_gap" in gaps
     assert any(gaps[k] > v for k, v in cell["limits"].items()
                if k in gaps), gaps
