@@ -861,8 +861,8 @@ def check_host001(ctx: FileContext):
 # ---------------------------------------------------------------------------
 # OBS001 — tracer/metrics call inside a jitted function
 # ---------------------------------------------------------------------------
-_OBS_METHODS = {"span", "event", "set_context", "flush", "counter", "gauge",
-                "histogram", "inc", "set", "observe", "wall_now"}
+_OBS_METHODS = {"span", "event", "phase", "set_context", "flush", "counter",
+                "gauge", "histogram", "inc", "set", "observe", "wall_now"}
 _OBS_RECEIVERS = ("tracer", "metrics")
 
 

@@ -79,6 +79,15 @@ def _tree_sum(parts):
         lambda *leaves: functools.reduce(jnp.add, leaves), *parts)
 
 
+def h2d_bytes(cohort: BucketedCohort) -> int:
+    """Bytes of host arrays one round of ``cohort`` hands to the device:
+    every bucket's ``xs``, ``ys`` and ``mask``, and the float32 eq.-(13)
+    weight of each of its client slots."""
+    return sum(cb.xs.nbytes + cb.ys.nbytes + cb.mask.nbytes
+               + cb.sizes.size * np.dtype(np.float32).itemsize
+               for cb in cohort.buckets)
+
+
 @dataclasses.dataclass
 class CohortEngineStats:
     """Cumulative counters over an engine's lifetime (all rounds)."""
@@ -87,6 +96,7 @@ class CohortEngineStats:
     compiled_signatures: int = 0   # distinct bucket shapes seen so far
     real_elements: int = 0         # batch elements actually drawn
     layout_elements: int = 0       # batch elements the padded layout ran
+    h2d_bytes: int = 0             # host array bytes handed to the device
     # mesh-sharded path only (all zero / 1.0 on a 1-shard engine):
     sharded_dispatches: int = 0    # bucket dispatches through shard_map
     shard_pad_clients: int = 0     # padding client slots in sharded layouts
@@ -169,11 +179,12 @@ class CohortEngine:
         On a sharded engine the planner additionally pads every bucket's
         client axis to a multiple of the shard count so ``shard_map``
         splits it without a remainder shard."""
-        return build_bucketed_cohort(x, y, pools, n_steps, rng,
-                                     max_batch=max_batch,
-                                     batch_align=self.batch_align,
-                                     client_align=self.client_align,
-                                     client_multiple=self.shards)
+        with self.tracer.phase("cohort.build"):
+            return build_bucketed_cohort(x, y, pools, n_steps, rng,
+                                         max_batch=max_batch,
+                                         batch_align=self.batch_align,
+                                         client_align=self.client_align,
+                                         client_multiple=self.shards)
 
     # -- execution ----------------------------------------------------------
     def _bucket_signature(self, cb) -> tuple:
@@ -218,6 +229,7 @@ class CohortEngine:
         st.compiled_signatures = len(self.signatures)
         st.real_elements += cohort.real_elements
         st.layout_elements += cohort.layout_elements
+        st.h2d_bytes += h2d_bytes(cohort)
         if self.shards > 1:
             st.sharded_dispatches += len(cohort.buckets)
             st.shard_pad_clients += sum(
@@ -272,8 +284,6 @@ class CohortEngine:
             m = tr.metrics
             m.counter("cohort.recompiled_signatures").inc(fresh)
             m.counter("cohort.bucket_dispatches").inc(len(cohort.buckets))
-            m.counter("cohort.real_elements").inc(cohort.real_elements)
-            m.counter("cohort.layout_elements").inc(cohort.layout_elements)
         # a faulted round may select a different compiled program than
         # the warm one (fused -> split), so the guard stands down for it
         warm = (self.guard and not faulted
@@ -321,8 +331,6 @@ class CohortEngine:
                     self.shards, c // self.shards).sum(axis=1)]
         tr.span("bucket_dispatch", f"C{c}xH{h}xB{b}",
                 dur_wall=time.perf_counter() - t0, **attrs)
-        tr.metrics.histogram("cohort.dispatch_wall_s").observe(
-            time.perf_counter() - t0)
 
     def _execute(self, params, cohort: BucketedCohort, lr: float,
                  total: int, corrupt: Sequence[int] = (),
@@ -341,54 +349,56 @@ class CohortEngine:
         weights = (w / max(1, total)).astype(np.float32)
         dropped: List[int] = []
 
-        if len(cohort.buckets) == 1 and self.donate and not (
-                corrupt or quarantine):
-            # fused fast path: local update + aggregate in ONE dispatch
-            # with the params buffer donated (in-place model update).
-            # Without donation the split path below wins — XLA:CPU
-            # schedules the two smaller programs better than one fused
-            # one, and there is no buffer to reuse anyway.
-            cb = cohort.buckets[0]
-            t0 = time.perf_counter() if trace else 0.0
-            new_params, losses = cohort_round_step_donated(
-                self.apply_fn, params, cb.xs, cb.ys, cb.mask, weights, lr)
-            if trace:
-                self._trace_dispatch(cb, (new_params, losses), t0)
-            loss_parts = [losses]
-        else:
-            stacked_parts, loss_parts = [], []
-            for bi, cb in enumerate(cohort.buckets):
+        with self.tracer.phase("cohort.dispatch"):
+            if len(cohort.buckets) == 1 and self.donate and not (
+                    corrupt or quarantine):
+                # fused fast path: local update + aggregate in ONE dispatch
+                # with the params buffer donated (in-place model update).
+                # Without donation the split path below wins — XLA:CPU
+                # schedules the two smaller programs better than one fused
+                # one, and there is no buffer to reuse anyway.
+                cb = cohort.buckets[0]
                 t0 = time.perf_counter() if trace else 0.0
-                stacked, losses = cohort_local_update(
-                    self.apply_fn, params, cb.xs, cb.ys, cb.mask, lr)
+                new_params, losses = cohort_round_step_donated(
+                    self.apply_fn, params, cb.xs, cb.ys, cb.mask, weights, lr)
                 if trace:
-                    self._trace_dispatch(cb, (stacked, losses), t0)
-                if corrupt:
-                    # fault injection: NaN-fill the victims' trained
-                    # models AFTER the update — every RNG draw is the
-                    # one the clean run makes
-                    rows = [row for row, m in
-                            enumerate(cohort.plans[bi].members)
-                            if m in corrupt]
-                    for row in rows:
-                        stacked = jax.tree_util.tree_map(
-                            lambda a: a.at[row].set(jnp.nan), stacked)
-                        losses = losses.at[row].set(jnp.nan)
-                stacked_parts.append(stacked)
-                loss_parts.append(losses)
-            if quarantine:
-                weights, dropped = self._quarantine_weights(
-                    cohort, stacked_parts, weights)
-                self.last_quarantined = len(dropped)
-            if quarantine and weights.sum() <= 0:
-                # every real update was non-finite: keep the previous
-                # model (the split path never donated params)
-                new_params = params
+                    self._trace_dispatch(cb, (new_params, losses), t0)
+                loss_parts = [losses]
             else:
-                new_params = fedavg_stacked_multi(stacked_parts, weights,
-                                                  donate=self.donate)
+                stacked_parts, loss_parts = [], []
+                for bi, cb in enumerate(cohort.buckets):
+                    t0 = time.perf_counter() if trace else 0.0
+                    stacked, losses = cohort_local_update(
+                        self.apply_fn, params, cb.xs, cb.ys, cb.mask, lr)
+                    if trace:
+                        self._trace_dispatch(cb, (stacked, losses), t0)
+                    if corrupt:
+                        # fault injection: NaN-fill the victims' trained
+                        # models AFTER the update — every RNG draw is the
+                        # one the clean run makes
+                        rows = [row for row, m in
+                                enumerate(cohort.plans[bi].members)
+                                if m in corrupt]
+                        for row in rows:
+                            stacked = jax.tree_util.tree_map(
+                                lambda a: a.at[row].set(jnp.nan), stacked)
+                            losses = losses.at[row].set(jnp.nan)
+                    stacked_parts.append(stacked)
+                    loss_parts.append(losses)
+                if quarantine:
+                    weights, dropped = self._quarantine_weights(
+                        cohort, stacked_parts, weights)
+                    self.last_quarantined = len(dropped)
+                if quarantine and weights.sum() <= 0:
+                    # every real update was non-finite: keep the previous
+                    # model (the split path never donated params)
+                    new_params = params
+                else:
+                    new_params = fedavg_stacked_multi(stacked_parts, weights,
+                                                      donate=self.donate)
 
-        losses = self._scatter_losses(cohort, loss_parts)
+        with self.tracer.phase("cohort.wait"):
+            losses = self._scatter_losses(cohort, loss_parts)
         if dropped:
             bad = set(dropped)
             losses = [v for i, v in enumerate(losses) if i not in bad]
@@ -483,22 +493,25 @@ class CohortEngine:
 
         parts, loss_parts = [], []
         off = 0
-        for cb in cohort.buckets:
-            c = cb.xs.shape[0]
-            wb = weights[off:off + c]
-            off += c
-            t0 = time.perf_counter() if trace else 0.0
-            # host numpy tensors go in directly: the step's in_shardings
-            # commit them onto the mesh, and the buffers are donated
-            part, losses = self._sharded_step(
-                params, cb.xs, cb.ys, cb.mask, wb, lr)
-            if trace:
-                self._trace_dispatch(cb, (part, losses), t0)
-            parts.append(part)
-            loss_parts.append(losses)
-        new_params = parts[0] if len(parts) == 1 else _tree_sum(
-            tuple(parts))
-        return new_params, self._scatter_losses(cohort, loss_parts)
+        with self.tracer.phase("cohort.dispatch"):
+            for cb in cohort.buckets:
+                c = cb.xs.shape[0]
+                wb = weights[off:off + c]
+                off += c
+                t0 = time.perf_counter() if trace else 0.0
+                # host numpy tensors go in directly: the step's
+                # in_shardings commit them onto the mesh, and the buffers
+                # are donated
+                part, losses = self._sharded_step(
+                    params, cb.xs, cb.ys, cb.mask, wb, lr)
+                if trace:
+                    self._trace_dispatch(cb, (part, losses), t0)
+                parts.append(part)
+                loss_parts.append(losses)
+            new_params = parts[0] if len(parts) == 1 else _tree_sum(
+                tuple(parts))
+        with self.tracer.phase("cohort.wait"):
+            return new_params, self._scatter_losses(cohort, loss_parts)
 
     @staticmethod
     def _scatter_losses(cohort: BucketedCohort,

@@ -512,12 +512,13 @@ class RegionTrainer:
         training metrics to :attr:`result`."""
         cfg = self.cfg
         tr = self.tracer
-        if tr.enabled:
-            # context BEFORE orch.step: dynamics samples (and emits
-            # `outage` events) inside it, at this round's start clock
-            tr.set_context(region=self._region_name, round=r,
-                           t_sim=self.orch.wall_clock)
-        rec = self.orch.step(r)
+        # context BEFORE orch.step: dynamics samples (and emits `outage`
+        # events) inside it, at this round's start clock; the phases'
+        # annotations carry it whether or not the tracer is enabled
+        tr.set_context(region=self._region_name, round=r,
+                       t_sim=self.orch.wall_clock)
+        with tr.phase("region.orchestrate"):
+            rec = self.orch.step(r)
         specs = (self.faults.at(r, cfg.region_index)
                  if self.faults is not None else ())
         crash = self._apply_latency_faults(rec, specs)
@@ -568,11 +569,13 @@ class RegionTrainer:
         if n_quar:
             tr.metrics.counter("quarantine.updates").inc(n_quar)
 
-        _, acc = evaluate(self.apply_fn, self.params, self.x_eval,
-                          self.y_eval)
+        with tr.phase("region.evaluate"):
+            _, acc = evaluate(self.apply_fn, self.params, self.x_eval,
+                              self.y_eval)
+            acc = float(acc)
         res = self.result
         res.times.append(self.orch.wall_clock)
-        res.accuracies.append(float(acc))
+        res.accuracies.append(acc)
         res.losses.append(float(np.mean(losses)) if losses
                           else float("nan"))
         res.participated.append(bool(losses))

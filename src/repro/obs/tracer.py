@@ -39,10 +39,28 @@ instrumented code computes — trajectories are bit-identical with
 tracing on or off at equal seeds (test-locked).
 
 The disabled path is a null object: ``resolve_obs(None)`` returns the
-shared :data:`NULL_TRACER` whose ``enabled`` flag is ``False``; hot
+shared :data:`NULL_TRACER` whose ``enabled`` flag is ``False`` and which
+records nothing (it keeps only the last context, for phase metadata); hot
 instrumentation sites guard on ``tracer.enabled`` so a disabled run
-pays one attribute load + branch per site (<2% on the cohort
-benchmark, gated by ``benchmarks/obs_overhead.py``).
+pays one attribute load + branch per site, and one profiler
+annotation per phase (<2% on the cohort benchmark, gated by
+``benchmarks/obs_overhead.py``).
+
+**Phases** put the host's work on the profiler's clock.  ``with
+tracer.phase("cohort.build"):`` opens a ``jax.profiler.TraceAnnotation``
+named ``repro.cohort.build`` whatever the tracer's state, so any
+``jax.profiler`` capture shows the phase beside the device's ops, with
+the tracer's current region and round as its metadata.  Phases nest; the
+stack opens ``repro.region.step`` (engine) around
+``repro.region.orchestrate``, ``repro.cohort.build``,
+``repro.cohort.dispatch``, ``repro.cohort.wait`` and
+``repro.region.evaluate``, and ``repro.engine.merge`` at each merge.  An
+enabled tracer also times each phase on the host clock into the
+histograms ``phase.<name>.wall_s`` (count = times the phase ran) and
+``phase.<name>.self_s`` (its duration less its child phases').  A
+disabled tracer reads no clock: a phase is then the annotation alone.
+``SAGINEngine.set_tracer`` points a built engine at another tracer and
+back, so a running job can be timed for a stretch of rounds.
 
 Do NOT call tracer/metrics methods inside ``jax.jit``-compiled
 functions — the call runs at trace time, not per execution (lint rule
@@ -165,6 +183,8 @@ class Tracer:
         self.ctx_region = ""
         self.ctx_round = -1
         self.ctx_t_sim = 0.0
+        # innermost open phase (enabled tracer only)
+        self._phase: Optional[_Phase] = None
 
     # -- clocks / context ---------------------------------------------------
     def wall_now(self) -> float:
@@ -174,8 +194,8 @@ class Tracer:
     def set_context(self, region: Optional[str] = None,
                     round: Optional[int] = None,
                     t_sim: Optional[float] = None) -> None:
-        if not self.enabled:
-            return
+        """Set the emission context.  Kept on a disabled tracer too: the
+        phases' profiler annotations carry its region and round."""
         if region is not None:
             self.ctx_region = region
         if round is not None:
@@ -214,6 +234,21 @@ class Tracer:
         """Zero-duration span (an instant on the timeline)."""
         return self.span(kind, name, **kw)
 
+    def phase(self, name: str, *, region: Optional[str] = None,
+              round: Optional[int] = None):
+        """Context manager: the host phase ``repro.<name>`` (module
+        docstring).  ``region``/``round`` override the context in the
+        annotation's metadata."""
+        # imported here: the report CLI reads traces without jax
+        from jax.profiler import TraceAnnotation
+        ann = TraceAnnotation(
+            f"repro.{name}",
+            region=self.ctx_region if region is None else region,
+            round=self.ctx_round if round is None else round)
+        if not self.enabled:
+            return ann
+        return _Phase(self, name, ann)
+
     # -- export -------------------------------------------------------------
     def flush(self, path: Optional[str] = None) -> Optional[str]:
         """Write the buffered spans to ``path`` (default: the config's).
@@ -234,8 +269,42 @@ class Tracer:
         return dest
 
 
+class _Phase:
+    """One open phase of an enabled tracer: the annotation, plus its wall
+    and self seconds into the tracer's histograms on exit."""
+
+    __slots__ = ("tracer", "name", "ann", "parent", "child_s", "t0")
+
+    def __init__(self, tracer: Tracer, name: str, ann):
+        self.tracer = tracer
+        self.name = name
+        self.ann = ann
+
+    def __enter__(self):
+        self.ann.__enter__()
+        tr = self.tracer
+        self.parent, tr._phase = tr._phase, self
+        self.child_s = 0.0
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self.t0
+        tr = self.tracer
+        tr._phase = self.parent
+        if self.parent is not None:
+            self.parent.child_s += dur
+        m = tr.metrics
+        m.histogram(f"phase.{self.name}.wall_s").observe(dur)
+        m.histogram(f"phase.{self.name}.self_s").observe(dur - self.child_s)
+        return self.ann.__exit__(*exc)
+
+
 #: Shared disabled tracer: every recording method early-returns, metrics
-#: are the shared null registry.  ``resolve_obs(None)`` hands this out.
+#: are the shared null registry.  It does keep the last ``set_context``
+#: (region, round, sim time), process-wide: every disabled user shares
+#: it, and it is read only for the metadata of phases opened without an
+#: explicit region and round.  ``resolve_obs(None)`` hands this out.
 NULL_TRACER = Tracer(ObsConfig(enabled=False))
 
 

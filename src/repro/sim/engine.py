@@ -278,7 +278,9 @@ class SAGINEngine:
             _, i, r = heapq.heappop(heap)
             self.step_order.append((i, r))
             trainer = self.trainers[i]
-            self.traces[i].records.append(trainer.step(r))
+            with self.tracer.phase("region.step",
+                                   region=trainer._region_name, round=r):
+                self.traces[i].records.append(trainer.step(r))
             nxt = r + 1
             at_boundary = (policy is not None
                            and (nxt % fed.every == 0
@@ -316,6 +318,27 @@ class SAGINEngine:
                           for i, t in enumerate(self.trainers)),
             barrier_round=barrier_round, trigger=trigger)
 
+    def set_tracer(self, obs):
+        """Point the engine, its trainers, their cohort engines, the
+        dynamics and the fault injector at the tracer ``obs`` resolves to
+        (:func:`repro.obs.resolve_obs`; ``None`` switches tracing off),
+        between rounds; returns the tracer it replaces, to switch back."""
+        from repro.obs import resolve_obs
+        tracer = resolve_obs(obs)
+        prev, self.tracer = self.tracer, tracer
+        for t in self.trainers:
+            t.tracer = tracer
+            if t.cohort_engine is not None:
+                t.cohort_engine.tracer = tracer
+            if t.orch.dynamics is not None:
+                t.orch.dynamics.tracer = tracer
+        for orch in self.orchestrators:
+            if orch.dynamics is not None:
+                orch.dynamics.tracer = tracer
+        if self.fault_injector is not None:
+            self.fault_injector.tracer = tracer
+        return prev
+
     def _policy_merge(self, policy, barrier_round: int,
                       trigger: Optional[int] = None):
         """Plan one merge with the federation policy and execute it:
@@ -323,6 +346,12 @@ class SAGINEngine:
         the plan's recipients (clock := merge time + ISL toll), and
         record the realized :class:`MergeEvent`.  A ``None`` plan skips
         the merge — no models move, no clocks change."""
+        from repro.obs import FEDERATION_TRACK
+        with self.tracer.phase("engine.merge", region=FEDERATION_TRACK,
+                               round=barrier_round):
+            self._merge(policy, barrier_round, trigger)
+
+    def _merge(self, policy, barrier_round: int, trigger: Optional[int]):
         from repro.fl.client import evaluate
 
         trainers = self.trainers

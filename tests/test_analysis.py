@@ -420,6 +420,18 @@ def test_obs001_positive_module_level_jit(tmp_path):
     assert rules_hit(out) == ["OBS001"]
 
 
+def test_obs001_positive_phase_inside_jit(tmp_path):
+    out = lint(tmp_path, """
+        import jax
+
+        @jax.jit
+        def step(tracer, x):
+            with tracer.phase("cohort.dispatch"):   # opens at trace time
+                return x * 2
+    """)
+    assert rules_hit(out) == ["OBS001"]
+
+
 def test_obs001_negative_outside_jit(tmp_path):
     out = lint(tmp_path, """
         import jax
