@@ -213,7 +213,8 @@ def _sliced_round(apply_fn, params, cohort, lr, n_slices):
         loss_parts.append(jnp.concatenate(bucket_losses))
     w = np.concatenate([cb.sizes for cb in cohort.buckets])
     new = fedavg_stacked_multi(parts, (w / w.sum()).astype(np.float32))
-    return new, CohortEngine._scatter_losses(cohort, loss_parts)
+    return new, CohortEngine._scatter_losses(cohort.plans, cohort.n_clients,
+                                             loss_parts)
 
 
 def _max_diffs(a, b) -> tuple:

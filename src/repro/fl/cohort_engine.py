@@ -53,6 +53,7 @@ to ``sharding="off"`` by construction (golden-locked in
 """
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
 import functools
 import time
@@ -248,11 +249,14 @@ class CohortEngine:
 
     def round(self, params, cohort: BucketedCohort, lr: float,
               total: int, corrupt: Sequence[int] = (),
-              quarantine: bool = False) -> Tuple[object, List[float]]:
+              quarantine: bool = False
+              ) -> Tuple[object, Sequence[float]]:
         """Train every bucket and aggregate — one FL round on device.
 
         Returns ``(new_global_params, losses)`` with ``losses`` the real
-        clients' mean local losses in canonical cohort order.  With
+        clients' mean local losses in canonical cohort order: a
+        :class:`ClientLosses` that reads them from the device on first
+        access, so the call returns once the round is dispatched.  With
         ``self.donate`` the params argument is consumed (see module
         docstring).
 
@@ -334,7 +338,8 @@ class CohortEngine:
 
     def _execute(self, params, cohort: BucketedCohort, lr: float,
                  total: int, corrupt: Sequence[int] = (),
-                 quarantine: bool = False) -> Tuple[object, List[float]]:
+                 quarantine: bool = False
+                 ) -> Tuple[object, Sequence[float]]:
         # host numpy tensors and scalars go into the jitted steps as-is:
         # jit commits them through the C++ shard_args path, which is one
         # copy and no python dispatch — an explicit jnp.asarray per
@@ -397,12 +402,8 @@ class CohortEngine:
                     new_params = fedavg_stacked_multi(stacked_parts, weights,
                                                       donate=self.donate)
 
-        with self.tracer.phase("cohort.wait"):
-            losses = self._scatter_losses(cohort, loss_parts)
-        if dropped:
-            bad = set(dropped)
-            losses = [v for i, v in enumerate(losses) if i not in bad]
-        return new_params, losses
+        return new_params, ClientLosses(cohort, loss_parts, self.tracer,
+                                        dropped)
 
     def _quarantine_weights(self, cohort: BucketedCohort,
                             stacked_parts: List, weights: np.ndarray
@@ -472,7 +473,7 @@ class CohortEngine:
             donate_argnums=(1, 2, 3, 4))
 
     def _execute_sharded(self, params, cohort: BucketedCohort, lr: float,
-                         total: int) -> Tuple[object, List[float]]:
+                         total: int) -> Tuple[object, "ClientLosses"]:
         """Dispatch every bucket through the sharded step.
 
         Weights are GLOBALLY normalized on the host (padding clients
@@ -510,15 +511,73 @@ class CohortEngine:
                 loss_parts.append(losses)
             new_params = parts[0] if len(parts) == 1 else _tree_sum(
                 tuple(parts))
-        with self.tracer.phase("cohort.wait"):
-            return new_params, self._scatter_losses(cohort, loss_parts)
+        return new_params, ClientLosses(cohort, loss_parts, self.tracer)
 
     @staticmethod
-    def _scatter_losses(cohort: BucketedCohort,
+    def _scatter_losses(plans, n_clients: int,
                         loss_parts: List) -> List[float]:
         """Map per-bucket loss vectors back to canonical client order."""
-        out = np.zeros(cohort.n_clients, dtype=np.float64)
-        for plan, losses in zip(cohort.plans, loss_parts):
+        out = np.zeros(n_clients, dtype=np.float64)
+        for plan, losses in zip(plans, loss_parts):
             vals = np.asarray(losses)[:len(plan.members)]
             out[list(plan.members)] = vals
         return [float(v) for v in out]
+
+
+class ClientLosses(collections.abc.Sequence):
+    """A round's real clients' mean local losses in canonical cohort
+    order, less the quarantined ones, read from the device on first
+    access.
+
+    ``len()`` is known on the host and reads nothing, so a caller can
+    launch the next round before this one's results exist.  The first
+    index, iteration or ``numpy.asarray`` reads every bucket's loss
+    vector at once, inside the phase ``cohort.wait`` tagged with the
+    region and round that launched the round, and keeps the floats;
+    later accesses read nothing.  ``jax.block_until_ready`` waits for
+    the vectors without reading them."""
+
+    def __init__(self, cohort: BucketedCohort, loss_parts: List, tracer,
+                 dropped: Sequence[int] = ()):
+        # the plans only: the cohort's host tensors are freed on return
+        self._plans = cohort.plans
+        self._n_clients = cohort.n_clients
+        self._dropped = frozenset(dropped)
+        self._parts = loss_parts
+        self._values: Optional[List[float]] = None
+        self._tracer = tracer
+        self._tag = dict(region=tracer.ctx_region, round=tracer.ctx_round)
+
+    def _read(self) -> List[float]:
+        if self._values is None:
+            with self._tracer.phase("cohort.wait", **self._tag):
+                values = CohortEngine._scatter_losses(
+                    self._plans, self._n_clients, self._parts)
+            self._values = [v for i, v in enumerate(values)
+                            if i not in self._dropped]
+            self._parts = None
+        return self._values
+
+    def __len__(self) -> int:
+        return self._n_clients - len(self._dropped)
+
+    def __getitem__(self, i):
+        return self._read()[i]
+
+    def __iter__(self):
+        return iter(self._read())
+
+    def __array__(self, dtype=None, copy=None):
+        return np.array(self._read(), dtype=dtype)
+
+    def __eq__(self, other):
+        # as the list of floats it stands for: round losses compare with
+        # ``==`` (the mesh engine's bit-identity lock does)
+        if isinstance(other, (ClientLosses, list)):
+            return self._read() == list(other)
+        return NotImplemented
+
+    def block_until_ready(self) -> "ClientLosses":
+        if self._parts is not None:
+            jax.block_until_ready(self._parts)
+        return self

@@ -8,10 +8,12 @@ latency. Produces accuracy-versus-training-time curves (Figs. 4, 6, 7).
 
 The unit of execution is :class:`RegionTrainer` — ONE region's complete
 FL job (dataset, pools, model, orchestrator), advanced one round at a
-time via :meth:`RegionTrainer.step`.  :func:`run_fl` is the thin
+time via :meth:`RegionTrainer.step` (:meth:`~RegionTrainer.launch`, then
+:meth:`~RegionTrainer.settle`).  :func:`run_fl` is the thin
 single-region wrapper that steps a trainer ``n_rounds`` times; the
-multi-region :class:`~repro.sim.engine.SAGINEngine` steps many trainers
-through its event heap and merges their models across regions
+multi-region :class:`~repro.sim.engine.SAGINEngine` launches many
+trainers' rounds through its event heap, each before it settles the one
+launched before it, and merges their models across regions
 (``fl.aggregation.staleness_weighted_merge``).
 
 Region addressing: with a scenario, all of a region's streams — dataset
@@ -60,8 +62,9 @@ produce the same accuracy trajectory up to float reduction-order noise.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Dict, List, Optional, Sequence, TYPE_CHECKING
+from typing import Deque, Dict, List, Optional, Sequence, TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
@@ -173,6 +176,18 @@ class FLResult:
             if a >= target:
                 return t
         return None
+
+
+@dataclasses.dataclass
+class _Launched:
+    """A launched round awaiting :meth:`RegionTrainer.settle`: what the
+    host decided at launch, and the device values still to read."""
+    round: int
+    rec: object                    # the round's RoundRecord
+    clock: float                   # the region's clock after the round
+    losses: Sequence[float]        # client losses (ClientLosses or floats)
+    acc: object                    # device scalar: accuracy on the eval set
+    portions: Dict[str, float]     # data share per layer
 
 
 def _build_orchestrator(cfg: FLConfig, sagin: SAGIN,
@@ -360,9 +375,13 @@ class RegionTrainer:
     Owns the region's dataset, index pools, model parameters, and SAGIN
     orchestrator; :meth:`step` executes one full round (orchestration,
     data placement, local training, aggregation, evaluation) and appends
-    to :attr:`result`.  Construction is the exact sequence the historic
-    ``run_fl`` body performed, so stepping a trainer ``n_rounds`` times
-    is trajectory-identical to the pre-refactor loop at equal seeds.
+    to :attr:`result`.  It is :meth:`launch`, which does the host's work
+    and dispatches the device's, then :meth:`settle`, which reads the
+    round's losses and accuracy back; the engine launches the next round
+    before it settles this one.  Construction is the exact sequence the
+    historic ``run_fl`` body performed, so stepping a trainer
+    ``n_rounds`` times is trajectory-identical to the pre-refactor loop
+    at equal seeds.
 
     The engine passes ``scenario``/``intervals`` so every region shares
     one propagation pass; standalone use needs only the config.  After a
@@ -433,7 +452,7 @@ class RegionTrainer:
         # records are not checkpointed)
         self._last_isl_scale = 1.0
         # dynamics emits `outage` events against the tracer's round
-        # context (set below in step()) instead of plumbing region
+        # context (set in launch()) instead of plumbing region
         # identity through the orchestrator call chain
         if self.orch.dynamics is not None:
             self.orch.dynamics.tracer = self.tracer
@@ -460,6 +479,8 @@ class RegionTrainer:
                 sharding=cfg.cohort_sharding)
 
         self.result = FLResult(cfg, [], [], [], [], [], [])
+        # launched rounds not yet settled, oldest first (launch / settle)
+        self._pending: Deque[_Launched] = collections.deque()
         eval_idx = self.rng.choice(len(self.ds.x_test),
                                    size=min(cfg.eval_size,
                                             len(self.ds.x_test)),
@@ -509,7 +530,23 @@ class RegionTrainer:
         """Execute FL round ``r``: orchestrate, place data, train every
         data-holding node, aggregate, evaluate.  Returns the round's
         :class:`~repro.core.scheduler.RoundRecord` and appends the
-        training metrics to :attr:`result`."""
+        training metrics to :attr:`result` (:meth:`launch`, then
+        :meth:`settle`)."""
+        rec = self.launch(r)
+        while self._pending:
+            self.settle()
+        return rec
+
+    def launch(self, r: int):
+        """Run round ``r`` up to and including the dispatch of its local
+        update, aggregate and evaluation, and return its
+        :class:`~repro.core.scheduler.RoundRecord`.
+
+        Everything the host decides (plan, pools, clock, the next round's
+        start model as a device future) is done on return; the round's
+        client losses and accuracy stay on the device until
+        :meth:`settle`, so the caller can orchestrate and dispatch other
+        rounds while this one trains."""
         cfg = self.cfg
         tr = self.tracer
         # context BEFORE orch.step: dynamics samples (and emits `outage`
@@ -572,26 +609,41 @@ class RegionTrainer:
         with tr.phase("region.evaluate"):
             _, acc = evaluate(self.apply_fn, self.params, self.x_eval,
                               self.y_eval)
-            acc = float(acc)
+        n_ground = sum(len(self.pools.ground_all(k))
+                       for k in range(cfg.n_devices))
+        n_air = sum(len(a) for a in self.pools.air)
+        self._pending.append(_Launched(
+            round=r, rec=rec, clock=self.orch.wall_clock, losses=losses,
+            acc=acc, portions={"ground": n_ground / total,
+                               "air": n_air / total,
+                               "space": len(self.pools.sat) / total}))
+        self._last_isl_scale = (float(rec.events.isl_scale)
+                                if rec.events is not None else 1.0)
+        return rec
+
+    def settle(self):
+        """Read back the oldest launched round's client losses and
+        accuracy (phase ``region.settle``) and append its training
+        metrics to :attr:`result`, as Python floats; returns its
+        :class:`~repro.core.scheduler.RoundRecord`."""
+        p = self._pending.popleft()
+        tr = self.tracer
+        with tr.phase("region.settle", region=self._region_name,
+                      round=p.round):
+            losses = list(p.losses)
+            acc = float(p.acc)
         res = self.result
-        res.times.append(self.orch.wall_clock)
+        res.times.append(p.clock)
         res.accuracies.append(acc)
         res.losses.append(float(np.mean(losses)) if losses
                           else float("nan"))
         res.participated.append(bool(losses))
-        res.latencies.append(rec.realized_latency)
-        res.cases.append(rec.plan.case)
-        n_ground = sum(len(self.pools.ground_all(k))
-                       for k in range(cfg.n_devices))
-        n_air = sum(len(a) for a in self.pools.air)
-        res.layer_portions.append({
-            "ground": n_ground / total, "air": n_air / total,
-            "space": len(self.pools.sat) / total})
-        self._last_isl_scale = (float(rec.events.isl_scale)
-                                if rec.events is not None else 1.0)
+        res.latencies.append(p.rec.realized_latency)
+        res.cases.append(p.rec.plan.case)
+        res.layer_portions.append(p.portions)
         if tr.enabled:
-            self._emit_round_spans(r, rec, res)
-        return rec
+            self._emit_round_spans(p.round, p.rec, res)
+        return p.rec
 
     def _apply_latency_faults(self, rec, specs):
         """Apply this round's latency-shaped faults to the round record
@@ -632,9 +684,11 @@ class RegionTrainer:
         return crash
 
     def _emit_round_spans(self, r: int, rec, res: FLResult):
-        """Trace one completed round: offload transfer, handover legs,
+        """Trace one settled round: offload transfer, handover legs,
         and the round span itself (``repro.obs``; enabled path only).
-        Purely observational — reads the round record, writes spans."""
+        Purely observational — reads the round record, writes spans
+        tagged with this round's region and round (by the time a round
+        settles, the tracer's context may belong to a later launch)."""
         tr = self.tracer
         t0 = rec.wall_clock_start
         plan = rec.plan
@@ -643,7 +697,8 @@ class RegionTrainer:
                  for cp in plan.clusters)
         down = sum(sum(cp.d_air_ground.values()) + cp.d_space_air
                    for cp in plan.clusters)
-        tr.span("offload", f"offload case{plan.case}", t_sim=t0,
+        at = dict(region=self._region_name, round=r)
+        tr.span("offload", f"offload case{plan.case}", t_sim=t0, **at,
                 case=plan.case, up_samples=up, down_samples=down,
                 bytes_moved=(up + down) * q_bits / 8.0)
         tr.metrics.counter("offload.bytes").inc((up + down) * q_bits / 8.0)
@@ -654,7 +709,7 @@ class RegionTrainer:
         prev = None
         for leg in sched.legs:
             if prev is not None and leg.handover_delay > 0:
-                tr.span("handover", f"sat{prev}->sat{leg.sat_index}",
+                tr.span("handover", f"sat{prev}->sat{leg.sat_index}", **at,
                         t_sim=t0 + leg.start_time - leg.handover_delay,
                         dur_sim=leg.handover_delay,
                         samples=leg.samples_processed)
@@ -665,7 +720,7 @@ class RegionTrainer:
         ev = rec.events
         uplink_delay = (sum(ev.uplink_delays.values())
                         if ev is not None else 0.0)
-        tr.span("round", f"{self._region_name}/r{r}", t_sim=t0,
+        tr.span("round", f"{self._region_name}/r{r}", t_sim=t0, **at,
                 dur_sim=rec.realized_latency,
                 case=plan.case, latency_analytic=rec.latency,
                 # the no-participant loss sentinel is NaN — not valid

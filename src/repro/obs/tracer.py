@@ -50,11 +50,13 @@ annotation per phase (<2% on the cohort benchmark, gated by
 tracer.phase("cohort.build"):`` opens a ``jax.profiler.TraceAnnotation``
 named ``repro.cohort.build`` whatever the tracer's state, so any
 ``jax.profiler`` capture shows the phase beside the device's ops, with
-the tracer's current region and round as its metadata.  Phases nest; the
-stack opens ``repro.region.step`` (engine) around
-``repro.region.orchestrate``, ``repro.cohort.build``,
-``repro.cohort.dispatch``, ``repro.cohort.wait`` and
-``repro.region.evaluate``, and ``repro.engine.merge`` at each merge.  An
+the tracer's current region and round as its metadata.  Phases nest;
+a region round opens ``repro.region.step`` (engine: the round's launch)
+around ``repro.region.orchestrate``, ``repro.cohort.build``,
+``repro.cohort.dispatch`` and ``repro.region.evaluate``, and later, once
+the engine has launched the next region round, ``repro.region.settle``
+around ``repro.cohort.wait`` (the read of its results); the engine
+opens ``repro.engine.merge`` at each merge.  An
 enabled tracer also times each phase on the host clock into the
 histograms ``phase.<name>.wall_s`` (count = times the phase ran) and
 ``phase.<name>.self_s`` (its duration less its child phases').  A
@@ -165,7 +167,7 @@ class Tracer:
 
     The tracer carries a mutable *context* (current region / round /
     simulated time) that the outermost instrumentation site
-    (``RegionTrainer.step``) sets once per round, so inner layers
+    (``RegionTrainer.launch``) sets once per round, so inner layers
     (``sim.dynamics``, ``CohortEngine``) can emit spans without
     plumbing region identity through every call signature.  The stack
     is single-threaded per run; no locking.

@@ -163,6 +163,9 @@ class SAGINEngine:
         self.federation = None
         self.fault_injector = None
         self.step_order: List[Tuple[int, int]] = []  # (region, round) pops
+        # FL mode: region rounds launched while an earlier one was still
+        # unsettled (mirrored to an enabled tracer's metrics)
+        self.overlapped_region_rounds = 0
         self.traces: List[RegionTrace] = [RegionTrace(region=r)
                                           for r in scenario.regions]
         self.orchestrators: List[SAGINOrchestrator] = []
@@ -254,7 +257,17 @@ class SAGINEngine:
                 final_merge: bool = True) -> List[RegionTrace]:
         """FL mode: event-step the region trainers; at merge boundaries
         consult the federation policy — barrier policies park regions
-        until all arrive, asynchronous policies plan per trigger."""
+        until all arrive, asynchronous policies plan per trigger.
+
+        The loop runs one region round ahead: it launches the popped
+        region round (:meth:`RegionTrainer.launch`: orchestration, cohort
+        build, dispatch), and only then settles the one launched before
+        it (:meth:`RegionTrainer.settle`: its losses and accuracy read
+        back), so the host prepares each region round while the device
+        trains the previous one.  Nothing the loop decides reads a
+        settled value: the heap orders by the clocks ``launch`` sets.
+        Every launched round is settled before each merge and before
+        ``run`` returns."""
         fed = self.federation
         policy = None
         if fed is not None and fed.every is not None:
@@ -274,13 +287,28 @@ class SAGINEngine:
                 for i, t in enumerate(self.trainers)]
         heapq.heapify(heap)
         waiting: List[Tuple[int, int]] = []  # (region, next_round) parked
+        tr = self.tracer
+        prev = None  # the trainer of the region round launched last
+
+        def settle_all():
+            # launch order: prev's round was launched before any other
+            first = [prev] if prev is not None else []
+            for t in first + self.trainers:
+                while t._pending:
+                    t.settle()
+
         while heap:
             _, i, r = heapq.heappop(heap)
             self.step_order.append((i, r))
             trainer = self.trainers[i]
-            with self.tracer.phase("region.step",
-                                   region=trainer._region_name, round=r):
-                self.traces[i].records.append(trainer.step(r))
+            if prev is not None and prev._pending:
+                self.overlapped_region_rounds += 1
+                if tr.enabled:
+                    tr.metrics.counter(
+                        "engine.overlapped_region_rounds").inc()
+            with tr.phase("region.step", region=trainer._region_name,
+                          round=r):
+                self.traces[i].records.append(trainer.launch(r))
             nxt = r + 1
             at_boundary = (policy is not None
                            and (nxt % fed.every == 0
@@ -288,6 +316,7 @@ class SAGINEngine:
             if at_boundary and policy.requires_barrier:
                 waiting.append((i, nxt))
                 if len(waiting) == len(self.trainers):
+                    settle_all()
                     self._policy_merge(policy, nxt)
                     for j, nr in waiting:
                         if nr < end:
@@ -296,9 +325,15 @@ class SAGINEngine:
                     waiting = []
             else:
                 if at_boundary:  # asynchronous boundary: no parking
+                    settle_all()
                     self._policy_merge(policy, nxt, trigger=i)
                 if nxt < end:
                     heapq.heappush(heap, (trainer.wall_clock, i, nxt))
+            if prev is not None and prev._pending:
+                # the region round launched before this one
+                prev.settle()
+            prev = trainer
+        settle_all()
         if policy is None and self.trainers:
             # no merging: the "global" model is undefined; expose None so
             # callers can tell one-global-model runs from independent ones

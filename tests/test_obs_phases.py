@@ -27,9 +27,12 @@ CFG = FLConfig(dataset="mnist", n_rounds=2, n_devices=4, n_air=1,
                h_local=2, train_fraction=0.005, eval_size=64, seed=0,
                execution="batched")
 
-#: the phases one batched region round opens, besides ``region.step``
-REGION_PHASES = ("region.orchestrate", "cohort.build", "cohort.dispatch",
-                 "cohort.wait", "region.evaluate")
+#: the phases one batched region round opens inside ``region.step`` (its
+#: launch) and inside ``region.settle`` (the read of its results)
+LAUNCH_PHASES = ("region.orchestrate", "cohort.build", "cohort.dispatch",
+                 "region.evaluate")
+SETTLE_PHASES = ("cohort.wait",)
+REGION_PHASES = LAUNCH_PHASES + SETTLE_PHASES
 
 
 def _trajectory(eng):
@@ -77,6 +80,9 @@ def runs(tmp_path_factory):
 
 
 def test_profiler_capture_nests_wait_inside_region_step(runs):
+    """Each region round's launch phases lie inside its ``region.step``
+    and its ``cohort.wait`` inside its ``region.settle`` (the engine
+    settles a region round after launching the next one)."""
     from jax.profiler import ProfileData
     path = glob.glob(f"{runs['prof']}/**/*.xplane.pb", recursive=True)[0]
     events = []
@@ -87,15 +93,29 @@ def test_profiler_capture_nests_wait_inside_region_step(runs):
             events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
                         {k: v for k, v in e.stats}) for e in line.events
                        if e.name.startswith("repro.")]
-    steps = [e for e in events if e[0] == "repro.region.step"]
-    waits = [e for e in events if e[0] == "repro.cohort.wait"]
-    assert len(steps) == len(XR2.regions) and len(waits) == len(steps)
-    for _, s, e, meta in waits:
-        outer = [st for st in steps if st[1] <= s and e <= st[2]]
-        assert len(outer) == 1
-        # the spans of one region round share its region and round
-        assert outer[0][3]["region"] == meta["region"]
-        assert outer[0][3]["round"] == meta["round"] == 1
+    def named(name):
+        return [e for e in events if e[0] == f"repro.{name}"]
+
+    def only_outer(inner, outers):
+        for _, s, e, meta in inner:
+            outer = [o for o in outers if o[1] <= s and e <= o[2]]
+            assert len(outer) == 1
+            # the spans of one region round share its region and round
+            assert outer[0][3]["region"] == meta["region"]
+            assert outer[0][3]["round"] == meta["round"] == 1
+
+    steps, settles = named("region.step"), named("region.settle")
+    assert len(steps) == len(settles) == len(XR2.regions)
+    assert {m["region"] for *_, m in settles} == {r.name for r in
+                                                  XR2.regions}
+    for name in LAUNCH_PHASES:
+        assert len(named(name)) == len(steps), name
+        only_outer(named(name), steps)
+    waits = named("cohort.wait")
+    assert len(waits) == len(settles)
+    only_outer(waits, settles)
+    assert not any(st[1] <= s and e <= st[2]
+                   for _, s, e, _ in waits for st in steps)
     merges = [e for e in events if e[0] == "repro.engine.merge"]
     assert [m[3]["round"] for m in merges] == [2]
 
@@ -103,14 +123,17 @@ def test_profiler_capture_nests_wait_inside_region_step(runs):
 def test_enabled_phases_count_every_region_round(runs):
     snap = runs["tracer"].metrics.snapshot("phase.")
     region_rounds = 2 * len(XR2.regions)
-    for name in ("region.step",) + REGION_PHASES:
+    for name in ("region.step", "region.settle") + REGION_PHASES:
         assert snap[f"phase.{name}.wall_s"]["count"] == region_rounds, name
     assert snap["phase.engine.merge.wall_s"]["count"] == 2
-    # a region step's phases lie inside it: its self time is the rest
-    step = snap["phase.region.step.wall_s"]["sum"]
-    inner = sum(snap[f"phase.{n}.wall_s"]["sum"] for n in REGION_PHASES)
-    assert snap["phase.region.step.self_s"]["sum"] == pytest.approx(
-        step - inner, rel=1e-9, abs=1e-9)
+    # a region step's launch phases lie inside it, and the wait inside
+    # the settle: each one's self time is the rest
+    for outer, phases in (("region.step", LAUNCH_PHASES),
+                          ("region.settle", SETTLE_PHASES)):
+        wall = snap[f"phase.{outer}.wall_s"]["sum"]
+        inner = sum(snap[f"phase.{n}.wall_s"]["sum"] for n in phases)
+        assert snap[f"phase.{outer}.self_s"]["sum"] == pytest.approx(
+            wall - inner, rel=1e-9, abs=1e-9), outer
 
 
 def test_disabled_phase_records_nothing(runs):
